@@ -70,6 +70,9 @@ def main() -> None:
         help="run the perf suites and write BENCH_batch.json / BENCH_time.json",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.emit_json is not None:
         _emit_json(args.emit_json)
         return

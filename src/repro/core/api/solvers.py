@@ -81,6 +81,7 @@ __all__ = [
     "build_coo_sketch",
     "build_mf_log_sketch",
     "build_mf_sketch",
+    "mf_sampling_factors",
     "mix_uniform",
     "sampling_probs",
 ]
@@ -155,6 +156,20 @@ def _mf_geometry(problem: OTProblem) -> PointCloudGeometry:
     return geom
 
 
+def mf_sampling_factors(problem: OTProblem):
+    """``(ra, rb, thin_scale)`` of the matrix-free draw: the eq. (9)
+    factors for OT (``thin_scale = None``); for UOT the normalized rank-1
+    ``(a_i b_j)^{lam/(2lam+eps)}`` proposal of eq. (11), thinned by
+    ``exp(-C thin_scale)`` with ``thin_scale = 1/(2lam+eps)``."""
+    if isinstance(problem, UOTProblem) and not problem.is_balanced:
+        lam, eps = float(problem.lam), float(problem.eps)
+        c_ab = lam / (2.0 * lam + eps)
+        qa, qb = problem.a ** c_ab, problem.b ** c_ab
+        return qa / jnp.sum(qa), qb / jnp.sum(qb), 1.0 / (2.0 * lam + eps)
+    ra, rb = sparsify.ot_sampling_prob_factors(problem.a, problem.b)
+    return ra, rb, None
+
+
 def build_mf_sketch(
     problem: OTProblem,
     key: jax.Array,
@@ -175,21 +190,8 @@ def build_mf_sketch(
     eps = float(problem.eps)
     cap = default_cap(s) if cap is None else cap
     entries = lambda r, c: geom.entries(r, c, eps, impl=impl)
-    if isinstance(problem, UOTProblem) and not problem.is_balanced:
-        lam = float(problem.lam)
-        c_ab = lam / (2.0 * lam + eps)
-        qa, qb = problem.a ** c_ab, problem.b ** c_ab
-        return sparsify.sparsify_coo_mf(
-            key,
-            qa / jnp.sum(qa),
-            qb / jnp.sum(qb),
-            s,
-            cap,
-            entries,
-            thin_scale=1.0 / (2.0 * lam + eps),
-        )
-    ra, rb = sparsify.ot_sampling_prob_factors(problem.a, problem.b)
-    return sparsify.sparsify_coo_mf(key, ra, rb, s, cap, entries)
+    ra, rb, thin_scale = mf_sampling_factors(problem)
+    return sparsify.sparsify_coo_mf(key, ra, rb, s, cap, entries, thin_scale=thin_scale)
 
 
 def build_coo_log_sketch(
@@ -247,22 +249,10 @@ def build_mf_log_sketch(
     geom = _mf_geometry(problem)
     eps = float(problem.eps)
     cap = default_cap(s) if cap is None else cap
-    if isinstance(problem, UOTProblem) and not problem.is_balanced:
-        lam = float(problem.lam)
-        c_ab = lam / (2.0 * lam + eps)
-        qa, qb = problem.a ** c_ab, problem.b ** c_ab
-        return sparsify.sparsify_coo_mf_log(
-            key,
-            qa / jnp.sum(qa),
-            qb / jnp.sum(qb),
-            s,
-            cap,
-            geom.cost_entries,
-            eps,
-            thin_scale=1.0 / (2.0 * lam + eps),
-        )
-    ra, rb = sparsify.ot_sampling_prob_factors(problem.a, problem.b)
-    return sparsify.sparsify_coo_mf_log(key, ra, rb, s, cap, geom.cost_entries, eps)
+    ra, rb, thin_scale = mf_sampling_factors(problem)
+    return sparsify.sparsify_coo_mf_log(
+        key, ra, rb, s, cap, geom.cost_entries, eps, thin_scale=thin_scale
+    )
 
 
 def _coo_value(problem: OTProblem, sk, res) -> jax.Array:

@@ -104,6 +104,13 @@ def wfr_log_kernel(
     return jnp.where(blocked, -jnp.inf, logk)
 
 
+def gather_points(pts: jax.Array, idx: jax.Array) -> list[jax.Array]:
+    """``pts[idx]`` as d arrays of shape (k,), one 1-D gather per
+    coordinate: a single (k, d) gather pads d to 128 lanes on the TPU
+    (~5 GB at k = 1e7)."""
+    return [pts[:, j][idx] for j in range(pts.shape[1])]
+
+
 def gathered_cost(
     x: jax.Array,
     y: jax.Array,
@@ -119,13 +126,10 @@ def gathered_cost(
     memory for k index pairs, never touching an (n, m) array. Blocked WFR
     entries (``d >= pi * eta``) come out ``+inf``, exactly as `wfr_cost`.
     """
-    xg, yg = x[rows], y[cols]
-    sq = jnp.maximum(
-        jnp.sum(xg * xg, axis=-1)
-        + jnp.sum(yg * yg, axis=-1)
-        - 2.0 * jnp.sum(xg * yg, axis=-1),
-        0.0,
-    )
+    xx = yy = xy = 0.0
+    for xj, yj in zip(gather_points(x, rows), gather_points(y, cols)):
+        xx, yy, xy = xx + xj * xj, yy + yj * yj, xy + xj * yj
+    sq = jnp.maximum(xx + yy - 2.0 * xy, 0.0)
     if cost == "sqeuclidean":
         return sq
     if cost == "euclidean":

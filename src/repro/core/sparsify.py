@@ -45,6 +45,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "BlockEllKernel",
+    "FactorDraw",
     "LogSparseKernelCOO",
     "SparseKernelCOO",
     "block_ell_matvec",
@@ -54,6 +55,7 @@ __all__ = [
     "coo_lse_row",
     "coo_matvec",
     "coo_rmatvec",
+    "factor_draw",
     "ot_sampling_prob_factors",
     "ot_sampling_probs",
     "ot_tile_probs",
@@ -316,6 +318,34 @@ def sparsify_coo_log(
     return sk, c_e
 
 
+class FactorDraw(NamedTuple):
+    """One Poissonized factorized draw (`factor_draw`)."""
+
+    counts: jax.Array  # (n,) per-row totals
+    rows: jax.Array  # (cap,) int32, sorted
+    cols: jax.Array  # (cap,) int32
+    u: jax.Array  # (cap,) the uniforms ``cols`` were inverted from
+    k_acc: jax.Array  # key left for UOT acceptance thinning
+
+
+def factor_draw(key: jax.Array, ra: jax.Array, rb: jax.Array, s: float, cap: int) -> FactorDraw:
+    """The Poissonized factorized draw of the matrix-free sketches.
+
+    Per-row totals ``counts ~ Poisson(s ra)``; slot ``i`` takes its row
+    from their cumulative sum and its column by inverse CDF on ``rb``.
+    Slots at or past ``sum(counts)`` are padding and park at row ``n - 1``.
+    """
+    n, m = ra.shape[0], rb.shape[0]
+    k_counts, k_cols, k_acc = jax.random.split(key, 3)
+    counts = jax.random.poisson(k_counts, s * ra)
+    rows = jnp.searchsorted(jnp.cumsum(counts), jnp.arange(cap), side="right")
+    rows = jnp.minimum(rows, n - 1).astype(jnp.int32)
+    u = jax.random.uniform(k_cols, (cap,), dtype=rb.dtype)
+    cols = jnp.searchsorted(jnp.cumsum(rb), u, side="right")
+    cols = jnp.minimum(cols, m - 1).astype(jnp.int32)
+    return FactorDraw(counts, rows, cols, u, k_acc)
+
+
 def sparsify_coo_mf(
     key: jax.Array,
     ra: jax.Array,
@@ -354,18 +384,13 @@ def sparsify_coo_mf(
     exactly the realized sketch.
     """
     n, m = ra.shape[0], rb.shape[0]
-    k_counts, k_cols, k_acc = jax.random.split(key, 3)
-    counts = jax.random.poisson(k_counts, s * ra)  # (n,) per-row totals
+    counts, rows, cols, _, k_acc = factor_draw(key, ra, rb, s, cap)
     total = jnp.sum(counts).astype(jnp.int32)
-    slot = jnp.arange(cap)
-    rows = jnp.searchsorted(jnp.cumsum(counts), slot, side="right")
-    rows = jnp.minimum(rows, n - 1).astype(jnp.int32)  # overflow slots park at n-1
-    u = jax.random.uniform(k_cols, (cap,), dtype=rb.dtype)
-    cols = jnp.searchsorted(jnp.cumsum(rb), u, side="right")
-    cols = jnp.minimum(cols, m - 1).astype(jnp.int32)
-    valid = slot < jnp.minimum(total, cap)
+    valid = jnp.arange(cap) < jnp.minimum(total, cap)
     k_e, c_e = entries_fn(rows, cols)
     rate = s * ra[rows] * rb[cols]  # E[multiplicity] per drawn entry
+    # floor for rate: a literal 1e-300 is 0 in float32
+    tiny = jnp.finfo(rate.dtype).tiny
     if thin_scale is not None:
         # acceptance K^{eps/(2lam+eps)} entirely in log space: the test
         # log U < -C thin_scale cannot flush to an always-False `U < 0`
@@ -378,12 +403,12 @@ def sparsify_coo_mf(
         alive = valid & (k_e > 0)
         logw = (
             jnp.log(jnp.where(alive, k_e, 1.0))
-            - jnp.log(jnp.maximum(rate, 1e-300))
+            - jnp.log(jnp.maximum(rate, tiny))
             - log_acc
         )
         vals = jnp.where(alive, jnp.exp(logw), 0.0)
     else:
-        vals = jnp.where(valid, k_e / jnp.maximum(rate, 1e-300), 0.0)
+        vals = jnp.where(valid, k_e / jnp.maximum(rate, tiny), 0.0)
     n_accepted = jnp.sum(vals != 0).astype(jnp.int32)  # pre-merge alive count
     # Merge duplicate draws (multiplicity >= 2 of one pair) so the sparse
     # objective's entry-wise entropy sees the summed plan mass, then compact
@@ -442,21 +467,15 @@ def sparsify_coo_mf_log(
     with the gathered costs index-aligned to the sketch arrays.
     """
     n, m = ra.shape[0], rb.shape[0]
-    k_counts, k_cols, k_acc = jax.random.split(key, 3)
-    counts = jax.random.poisson(k_counts, s * ra)  # (n,) per-row totals
+    counts, rows, cols, _, k_acc = factor_draw(key, ra, rb, s, cap)
     total = jnp.sum(counts).astype(jnp.int32)
-    slot = jnp.arange(cap)
-    rows = jnp.searchsorted(jnp.cumsum(counts), slot, side="right")
-    rows = jnp.minimum(rows, n - 1).astype(jnp.int32)  # overflow slots park at n-1
-    u = jax.random.uniform(k_cols, (cap,), dtype=rb.dtype)
-    cols = jnp.searchsorted(jnp.cumsum(rb), u, side="right")
-    cols = jnp.minimum(cols, m - 1).astype(jnp.int32)
-    valid = slot < jnp.minimum(total, cap)
+    valid = jnp.arange(cap) < jnp.minimum(total, cap)
     c_e = cost_entries_fn(rows, cols)
+    tiny = jnp.finfo(rb.dtype).tiny  # a literal 1e-300 is 0 in float32
     lograte = (
         jnp.log(jnp.asarray(s, rb.dtype))
-        + jnp.log(jnp.maximum(ra[rows], 1e-300))
-        + jnp.log(jnp.maximum(rb[cols], 1e-300))
+        + jnp.log(jnp.maximum(ra[rows], tiny))
+        + jnp.log(jnp.maximum(rb[cols], tiny))
     )
     if thin_scale is not None:
         log_acc = -c_e * thin_scale  # blocked (C = +inf) -> -inf, rejected

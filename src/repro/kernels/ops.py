@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.kernels import fused_sinkhorn as _fs
 from repro.kernels import block_ell as _be
+from repro.core.geometry import gather_points
 from repro.core.sinkhorn import SinkhornResult, generic_scaling_loop
 
 __all__ = [
@@ -119,28 +120,35 @@ def gathered_kernel(
     eps: float,
     cost: str = "sqeuclidean",
     eta: float = 1.0,
-    block_s: int = 1024,
+    block_s: int = 32768,
     interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """``(K_e, C_e) = (exp(-C(x_i,y_j)/eps), C(x_i,y_j))`` at k index pairs.
 
     The matrix-free sketch's kernel evaluation: XLA gathers the two
     support-point blocks (O(k d) HBM traffic), the Pallas kernel fuses the
-    cost + exponential per (block_s, d) VMEM chunk. WFR blocked pairs map
-    to exactly ``(0, +inf)``. Shapes: (n,d),(m,d),(k,),(k,) -> ((k,),(k,)).
+    cost + exponential per chunk of ``block_s`` entries (shrunk for small
+    k or large d). WFR blocked pairs map to exactly ``(0, +inf)``.
+    Shapes: (n,d),(m,d),(k,),(k,) -> ((k,),(k,)).
     """
-    interpret = _interpret_default() if interpret is None else interpret
-    k = rows.shape[0]
-    dp = _round_up(x.shape[1], 128)
-    kp = _round_up(max(k, 1), block_s)
-    xg = _pad_to(_pad_to(x.astype(jnp.float32)[rows], dp, 1), kp, 0)
-    yg = _pad_to(_pad_to(y.astype(jnp.float32)[cols], dp, 1), kp, 0)
-    from repro.kernels.gather_kernel import gathered_kernel_call
+    from repro.kernels.gather_kernel import LANES, gathered_kernel_call
 
+    interpret = _interpret_default() if interpret is None else interpret
+    k, d = rows.shape[0], x.shape[1]
+    tile = 8 * LANES
+    # at most ~2 MiB of VMEM per input block, at least one (8, 128) tile
+    block_s = max(tile, min(block_s, (2 ** 19 // d) // tile * tile))
+    block_s = min(block_s, _round_up(max(k, 1), tile))
+    kp = _round_up(max(k, 1), block_s)
+    # padded pairs gather point 0 (a valid index); sliced away below
+    rows, cols = _pad_to(rows, kp, 0, value=0), _pad_to(cols, kp, 0, value=0)
+    # (d, k/128, 128) coordinate planes: entries on lanes
+    xg = jnp.stack(gather_points(x.astype(jnp.float32), rows)).reshape(d, -1, LANES)
+    yg = jnp.stack(gather_points(y.astype(jnp.float32), cols)).reshape(d, -1, LANES)
     k_e, c_e = gathered_kernel_call(
         xg, yg, eps=eps, cost=cost, eta=eta, block_s=block_s, interpret=interpret
     )
-    return k_e[:k, 0], c_e[:k, 0]
+    return k_e.reshape(-1)[:k], c_e.reshape(-1)[:k]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -9,12 +9,8 @@ __all__ = ["make_production_mesh", "make_test_mesh"]
 
 
 def _make_mesh(shape, axes):
-    # axis_types landed after jax 0.4.x; Auto is the default there anyway,
-    # so omit the kwarg on versions that predate jax.sharding.AxisType.
-    if hasattr(jax.sharding, "AxisType"):
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,6 +32,7 @@ from repro.batch import BucketedExecutor
 from repro.batch.problems import bucket_shape
 from repro.core import Geometry, OTProblem, PointCloudGeometry, UOTProblem, s0, solve
 from repro.core.api.solution import Solution
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs.metrics import MetricsRegistry
 from repro.robust.breaker import BreakerPolicy, CircuitBreaker
 
@@ -514,6 +515,7 @@ def main() -> None:
     ap.add_argument("--no-warmup", action="store_true",
                     help="include first-dispatch compiles in the timed run")
     args = ap.parse_args()
+    use_compile_cache()
 
     sizes = [int(v) for v in args.sizes.split(",")]
     problems = _make_request_problems(

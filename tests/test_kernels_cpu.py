@@ -37,6 +37,11 @@ def _points(key, n, d, lo=0.0, hi=1.0):
     return jax.random.uniform(key, (n, d), jnp.float32, lo, hi)
 
 
+def _planes(pts):
+    """(S, d) gathered points -> the kernel's (d, S/128, 128) layout."""
+    return pts.T.reshape(pts.shape[1], -1, 128)
+
+
 # --------------------------------------------------------------------------
 # online_lse_call (raw, pre-padded shapes)
 # --------------------------------------------------------------------------
@@ -105,12 +110,14 @@ def test_gathered_kernel_call_sqeuclidean(shape):
     rows = jnp.asarray(rng.integers(0, n, s), jnp.int32)
     cols = jnp.asarray(rng.integers(0, m, s), jnp.int32)
     k_out, c_out = gathered_kernel_call(
-        x[rows], y[cols], eps=0.05, block_s=512, interpret=True
+        _planes(x[rows]), _planes(y[cols]), eps=0.05, block_s=1024,
+        interpret=True,
     )
+    assert k_out.shape == c_out.shape == (s // 128, 128)
     k_ref, c_ref = gathered_kernel_ref(x, y, rows, cols, eps=0.05)
-    np.testing.assert_allclose(np.asarray(c_out[:, 0]), np.asarray(c_ref),
+    np.testing.assert_allclose(np.asarray(c_out).ravel(), np.asarray(c_ref),
                                rtol=2e-4, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(k_out[:, 0]), np.asarray(k_ref),
+    np.testing.assert_allclose(np.asarray(k_out).ravel(), np.asarray(k_ref),
                                rtol=2e-3, atol=1e-6)
 
 
@@ -128,20 +135,21 @@ def test_gathered_kernel_call_wfr_blocked_is_exactly_zero():
     rows = jnp.asarray(rng.integers(0, 256, s), jnp.int32)
     cols = jnp.asarray(rng.integers(0, 256, s), jnp.int32)
     k_out, c_out = gathered_kernel_call(
-        x[rows], x[cols], eps=0.1, cost="wfr", eta=eta, block_s=512,
-        interpret=True,
+        _planes(x[rows]), _planes(x[cols]), eps=0.1, cost="wfr", eta=eta,
+        block_s=1024, interpret=True,
     )
+    k_out, c_out = np.asarray(k_out).ravel(), np.asarray(c_out).ravel()
     k_ref, c_ref = gathered_kernel_ref(x, x, rows, cols, eps=0.1, cost="wfr",
                                        eta=eta)
     blocked = np.isinf(np.asarray(c_ref))
     assert 0.1 < blocked.mean() < 0.9  # branch genuinely taken
-    np.testing.assert_array_equal(np.asarray(k_out[:, 0])[blocked], 0.0)
-    assert np.all(np.isinf(np.asarray(c_out[:, 0])[blocked]))
+    np.testing.assert_array_equal(k_out[blocked], 0.0)
+    assert np.all(np.isinf(c_out[blocked]))
     ok = ~blocked
-    np.testing.assert_allclose(np.asarray(k_out[:, 0])[ok],
-                               np.asarray(k_ref)[ok], rtol=2e-3, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(c_out[:, 0])[ok],
-                               np.asarray(c_ref)[ok], rtol=2e-3, atol=1e-5)
+    np.testing.assert_allclose(k_out[ok], np.asarray(k_ref)[ok], rtol=2e-3,
+                               atol=1e-6)
+    np.testing.assert_allclose(c_out[ok], np.asarray(c_ref)[ok], rtol=2e-3,
+                               atol=1e-5)
 
 
 def test_gathered_kernel_wrapper_pads_and_slices():

@@ -14,7 +14,7 @@ from repro.models import init_decode_state, init_params, loss_fn
 def _mesh2x2():
     # 1-device-safe fake mesh construction is not possible; these tests use
     # spec construction only (no placement), so a 1x1 mesh suffices when only
-    # one device exists. make_test_mesh handles jax versions without AxisType.
+    # one device exists.
     from repro.launch.mesh import make_test_mesh
 
     if len(jax.devices()) >= 4:

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import ClosedJaxpr
 
 from repro.core import (
     Geometry,
@@ -284,12 +285,12 @@ def _max_aval_elems(jaxpr) -> int:
                     biggest = max(biggest, int(np.prod(shape)))
             for param in eqn.params.values():
                 for sub in jax.tree_util.tree_leaves(
-                    param, is_leaf=lambda p: isinstance(p, jax.core.ClosedJaxpr)
+                    param, is_leaf=lambda p: isinstance(p, ClosedJaxpr)
                 ):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                    if isinstance(sub, ClosedJaxpr):
                         walk(sub.jaxpr)
 
-    walk(jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr)
+    walk(jaxpr.jaxpr if isinstance(jaxpr, ClosedJaxpr) else jaxpr)
     return biggest
 
 
