@@ -85,7 +85,6 @@ def main() -> None:
         bench_rmae_uot,
         bench_rmae_vs_eps,
         bench_rmae_vs_n,
-        bench_roofline,
         bench_router,
         bench_scale,
         bench_time,
@@ -110,8 +109,6 @@ def main() -> None:
             s_mult=16)),
         ("router (MoE spar-sink)", lambda: bench_router.run(n_tokens=1024)),
         ("batch (executor vs loop)", lambda: bench_batch.run()),
-        ("roofline (dry-run artifacts)", lambda: bench_roofline.summarize(
-            bench_roofline.best_artifact(), "1pod")),
     ]
     t0 = time.time()
     for name, fn in suites:

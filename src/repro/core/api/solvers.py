@@ -73,6 +73,7 @@ from repro.core.spar_sink import (
     log_plan_entries,
 )
 from repro.obs.certify import dense_certificate, importance_ess, sparse_certificate
+from repro.obs.spans import span
 from repro.obs.trace import SolverTrace, sketch_diagnostics
 
 __all__ = [
@@ -660,19 +661,29 @@ def _solve_spar_sink_log(
     sweep) cannot underflow the solve the way the scaling-domain sketch
     does. Returns a ``domain="log"`` `Solution`; plan and objective are
     evaluated from the potentials.
+
+    The solve's phases are host spans (`repro.obs.span`, recorded into
+    `repro.obs.default_registry`): ``spar_sink.solve`` around
+    ``spar_sink.sketch``, ``spar_sink.loop``, ``spar_sink.objective`` and,
+    with ``certify``, ``spar_sink.certify``.
     """
-    sk, c_e = build_coo_log_sketch(
-        problem, key, s, cap=cap, probs=probs, shrinkage=shrinkage
-    )
-    res = _sparse_log_loop(problem, sk, tol, max_iter, trace, init=init)
-    value = _coo_log_value(problem, sk, c_e, res)
-    cert = None
-    if certify:
-        cert = _sparse_cert(problem, sk, res, value, c_e, log_domain=True)
-    return _coo_log_solution(
-        "spar_sink_log", problem, sk, res, value,
-        sketch_stats=_sketch_stats(sk, trace), certificate=cert,
-    )
+    with span("spar_sink.solve"):
+        with span("spar_sink.sketch"):
+            sk, c_e = build_coo_log_sketch(
+                problem, key, s, cap=cap, probs=probs, shrinkage=shrinkage
+            )
+        with span("spar_sink.loop"):
+            res = _sparse_log_loop(problem, sk, tol, max_iter, trace, init=init)
+        with span("spar_sink.objective"):
+            value = _coo_log_value(problem, sk, c_e, res)
+        cert = None
+        if certify:
+            with span("spar_sink.certify"):
+                cert = _sparse_cert(problem, sk, res, value, c_e, log_domain=True)
+        return _coo_log_solution(
+            "spar_sink_log", problem, sk, res, value,
+            sketch_stats=_sketch_stats(sk, trace), certificate=cert,
+        )
 
 
 @register_solver("spar_sink_mf")
@@ -715,6 +726,8 @@ def _solve_spar_sink_mf(
     PRNG key; only the objective differs (gathered vs dense-indexed costs,
     equal up to rounding). Combined with ``stabilize=True`` it draws the
     ``spar_sink_log`` support instead.
+
+    Both domains record the phase spans of ``spar_sink_log``.
     """
     geom = _mf_geometry(problem)
     if init is not None and not stabilize:
@@ -722,39 +735,48 @@ def _solve_spar_sink_mf(
             "init= (warm-started potentials) requires the log-domain "
             "stabilize=True path"
         )
-    if stabilize:
-        if shared_variates:
-            sk, c_e = build_coo_log_sketch(problem, key, s, cap=cap)
-        else:
-            sk, c_e = build_mf_log_sketch(problem, key, s, cap=cap)
-        res = _sparse_log_loop(problem, sk, tol, max_iter, trace, init=init)
-        value = _coo_log_value(problem, sk, c_e, res)
+    with span("spar_sink.solve"):
+        if stabilize:
+            with span("spar_sink.sketch"):
+                if shared_variates:
+                    sk, c_e = build_coo_log_sketch(problem, key, s, cap=cap)
+                else:
+                    sk, c_e = build_mf_log_sketch(problem, key, s, cap=cap)
+            with span("spar_sink.loop"):
+                res = _sparse_log_loop(problem, sk, tol, max_iter, trace, init=init)
+            with span("spar_sink.objective"):
+                value = _coo_log_value(problem, sk, c_e, res)
+            cert = None
+            if certify:
+                with span("spar_sink.certify"):
+                    cert = _sparse_cert(problem, sk, res, value, c_e, log_domain=True)
+            return _coo_log_solution(
+                "spar_sink_mf", problem, sk, res, value,
+                sketch_stats=_sketch_stats(sk, trace), certificate=cert,
+            )
+        with span("spar_sink.sketch"):
+            if shared_variates:
+                sk = build_coo_sketch(problem, key, s, cap=cap)  # guarded dense draw
+                c_e = geom.cost_entries(sk.rows, sk.cols)
+            else:
+                sk, c_e = build_mf_sketch(problem, key, s, cap=cap, impl=impl)
+        with span("spar_sink.loop"):
+            res = _coo_scaling_loop(problem, sk, tol, max_iter, trace)
+        with span("spar_sink.objective"):
+            if isinstance(problem, UOTProblem) and not problem.is_balanced:
+                value = coo_objective_uot_entries(
+                    sk, c_e, res, problem.a, problem.b, float(problem.lam), problem.eps
+                )
+            else:
+                value = coo_objective_ot_entries(sk, c_e, res, problem.eps)
         cert = None
         if certify:
-            cert = _sparse_cert(problem, sk, res, value, c_e, log_domain=True)
-        return _coo_log_solution(
+            with span("spar_sink.certify"):
+                cert = _sparse_cert(problem, sk, res, value, c_e, log_domain=False)
+        return _coo_solution(
             "spar_sink_mf", problem, sk, res, value,
             sketch_stats=_sketch_stats(sk, trace), certificate=cert,
         )
-    if shared_variates:
-        sk = build_coo_sketch(problem, key, s, cap=cap)  # guarded dense draw
-        c_e = geom.cost_entries(sk.rows, sk.cols)
-    else:
-        sk, c_e = build_mf_sketch(problem, key, s, cap=cap, impl=impl)
-    res = _coo_scaling_loop(problem, sk, tol, max_iter, trace)
-    if isinstance(problem, UOTProblem) and not problem.is_balanced:
-        value = coo_objective_uot_entries(
-            sk, c_e, res, problem.a, problem.b, float(problem.lam), problem.eps
-        )
-    else:
-        value = coo_objective_ot_entries(sk, c_e, res, problem.eps)
-    cert = None
-    if certify:
-        cert = _sparse_cert(problem, sk, res, value, c_e, log_domain=False)
-    return _coo_solution(
-        "spar_sink_mf", problem, sk, res, value,
-        sketch_stats=_sketch_stats(sk, trace), certificate=cert,
-    )
 
 
 @register_solver("rand_sink")

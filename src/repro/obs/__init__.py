@@ -1,6 +1,6 @@
 """repro.obs — observability: jit-safe solver traces + host-side metrics.
 
-Three layers (see ISSUE 7 / README "Observability"):
+Four layers (see README "Observability"):
 
 * `trace`: fixed-size ring-buffer iteration telemetry carried through the
   ``lax.while_loop`` solver cores (`SolverTrace`), sketch-quality stats
@@ -17,10 +17,19 @@ Three layers (see ISSUE 7 / README "Observability"):
   p50-p95-p99 histograms) instrumenting `BucketedExecutor` and
   ``serve_ot``'s `OTServer`; `export` renders JSON events or
   Prometheus text (cumulative ``_bucket`` histogram exposition).
+* `spans`: `span`, a host span at a phase boundary of a solve. The
+  Spar-Sink solvers (``spar_sink_mf``, ``spar_sink_log``) open
+  ``spar_sink.solve`` and inside it ``spar_sink.sketch``,
+  ``spar_sink.loop``, ``spar_sink.objective`` and, with ``certify=True``,
+  ``spar_sink.certify``. Each is a ``jax.profiler.TraceAnnotation``, so a
+  profiler trace puts device programs and device idle time on the phase
+  that launched them, and each records its host seconds into
+  `default_registry` as the histogram ``<name>_seconds``:
+  ``spar_sink_sketch_seconds`` and so on in ``export("prometheus")``.
 * profiling: ``tools/profile_solve.py`` compiles any registered method and
-  reports XLA cost-analysis flops/bytes per iteration;
-  ``benchmarks/bench_serve.py`` turns the serving path into a sustained
-  requests/sec + tail-latency benchmark (``BENCH_serve.json``).
+  reports XLA cost-analysis flops/bytes per iteration; the on-chip
+  benchmark (``bench/``, ``BENCHMARK.json``) reduces a profiler trace of
+  the solve to per-phase device time and idle time.
 """
 from repro.obs.certify import (
     DEFAULT_Z,
@@ -36,6 +45,7 @@ from repro.obs.metrics import (
     default_registry,
     export,
 )
+from repro.obs.spans import span
 from repro.obs.trace import (
     DEFAULT_TRACE_LEN,
     Diagnostics,
@@ -66,6 +76,7 @@ __all__ = [
     "record_iteration",
     "resolve_trace_len",
     "sketch_diagnostics",
+    "span",
     "sparse_certificate",
     "trim_trace",
 ]

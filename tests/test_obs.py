@@ -8,6 +8,8 @@ Covers the PR-7 observability contract:
 * trace correctness: matvec accounting, ring-buffer wrap, chronological
   unroll, batched slicing;
 * sketch diagnostics (nnz/fill/ESS/acceptance/merge-rate);
+* the Spar-Sink solves' phase spans: one registry observation a phase, and
+  spans nested in a profiler trace that leave the result bitwise unchanged;
 * `MetricsRegistry` semantics (quantiles, windowing, atomicity, export
   formats) and the executor/serving instrumentation built on it;
 * status propagation through composite paths (divergence, barycenters,
@@ -522,6 +524,67 @@ def test_export_json_and_prometheus():
     assert "buckets" not in by_name["serve.latency_seconds"]
     with pytest.raises(ValueError):
         export("xml", reg)
+
+
+# --------------------------------------------------------------------------
+# Phase spans of the Spar-Sink solves
+# --------------------------------------------------------------------------
+
+PHASES = ("spar_sink.solve", "spar_sink.sketch", "spar_sink.loop", "spar_sink.objective")
+
+
+def _cloud(n=64, seed=0):
+    rng = np.random.default_rng(seed)
+    a = jnp.full((n,), 1.0 / n)
+    return OTProblem(PointCloudGeometry(jnp.asarray(rng.uniform(size=(n, 3)))), a, a, 0.1)
+
+
+@pytest.mark.parametrize("certify", [False, True])
+@pytest.mark.parametrize("method,opts", [("spar_sink_mf", {"stabilize": False}),
+                                         ("spar_sink_mf", {"stabilize": True}),
+                                         ("spar_sink_log", {})])
+def test_spar_sink_phases_record_once_into_injected_registry(method, opts, certify,
+                                                            monkeypatch):
+    import repro.obs.spans
+
+    reg = MetricsRegistry()
+    monkeypatch.setattr(repro.obs.spans, "default_registry", reg)
+    solve(_cloud(), method=method, key=jax.random.PRNGKey(0), s=2000.0, certify=certify,
+          **opts)
+    counts = {n: h["count"] for n, h in reg.snapshot()["histograms"].items()}
+    want = PHASES + (("spar_sink.certify",) if certify else ())
+    assert counts == {f"{p}_seconds": 1 for p in want}
+
+
+def test_spar_sink_spans_change_no_result_and_nest_in_the_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    p = _cloud()
+
+    def run():
+        sol = solve(p, method="spar_sink_mf", stabilize=True, key=jax.random.PRNGKey(1),
+                    s=2000.0)
+        return jax.block_until_ready((sol.value, *sol.potentials))
+
+    off = run()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        on = run()
+    finally:
+        jax.profiler.stop_trace()
+    for x, y in zip(off, on):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    pd = ProfileData.from_file(str(next(tmp_path.glob("**/*.xplane.pb"))))
+    spans = [(line.name, ev.start_ns, ev.end_ns, ev.name)
+             for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("spar_sink.")]
+    assert sorted(s[3] for s in spans) == sorted(PHASES)
+    (outer,) = [s for s in spans if s[3] == "spar_sink.solve"]
+    for line, t0, t1, _ in spans:
+        assert line == outer[0] and outer[1] <= t0 <= t1 <= outer[2]
 
 
 # --------------------------------------------------------------------------
