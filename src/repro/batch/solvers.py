@@ -36,6 +36,7 @@ to per-problem ``build_coo_sketch``:
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple
 
 import jax
@@ -57,6 +58,7 @@ from repro.obs.certify import (
     importance_ess,
     sparse_certificate,
 )
+from repro.obs.metrics import default_registry
 from repro.obs.trace import (
     SolverTrace,
     empty_trace,
@@ -920,6 +922,30 @@ def batched_solve_spar_sink_log(
     return _batched_sketch_log_solve(bp, sketch, tol, max_iter, trace, certify)
 
 
+class _SortedLogLayout(NamedTuple):
+    """A sorted sketch's column order, laid out once per solve: the rows
+    and log-values of ``csort``'s permutation, and where the segments of
+    the row order and the column order lie (`sparsify.SortedSegments`)."""
+
+    rows_cs: jax.Array  # (B, cap) int32
+    logvals_cs: jax.Array  # (B, cap)
+    row_seg: sparsify.SortedSegments
+    col_seg: sparsify.SortedSegments
+
+
+@functools.partial(jax.jit, static_argnames=("n", "m"))
+def _sorted_log_layout(rows, cols, logvals, csort, *, n: int, m: int):
+    """One program, before the loop: O(cap) gathers and O((n + m) log cap)
+    searches, so no iteration permutes its summands into column order."""
+    take = functools.partial(jnp.take_along_axis, indices=csort, axis=1)
+    return _SortedLogLayout(
+        take(rows),
+        take(logvals),
+        sparsify.sorted_segments(rows, n),
+        sparsify.sorted_segments(take(cols), m),
+    )
+
+
 def sparse_log_potentials(
     rows: jax.Array,
     cols: jax.Array,
@@ -944,34 +970,39 @@ def sparse_log_potentials(
 
     Sharing the exact computation matters: the segment-logsumexp contains
     ``exp``/``log`` whose fused codegen XLA may legally vary by a ulp
-    between differently-shaped programs, while this flat batched reduction
-    is B-invariant — so per-problem and batched results agree **bitwise**
+    between differently-shaped programs, while this batched reduction is
+    B-invariant — so per-problem and batched results agree **bitwise**
     per element. Returns ``(f, g, n_iter, err, status)``, all (B, ·);
     ``trace`` (static) appends a batched `repro.obs.SolverTrace`.
-    """
-    from repro.kernels.ops import batched_coo_logsumexp
 
-    sorted_ = csort is not None
-    if sorted_:
-        cols_sorted = jnp.take_along_axis(cols, csort, axis=1)
+    Sketches sorted by construction (``csort`` given) are laid out in
+    column order once (`_sorted_log_layout`), and each half-step is one
+    segmented scan (`sparsify.sorted_segment_logsumexp`), with no scatter;
+    ``csort=None`` falls back to the scatter `batched_coo_logsumexp`. Each
+    call adds 1 to the counter ``spar_sink.loop_sorted_scan`` or
+    ``spar_sink.loop_scatter`` of `repro.obs.default_registry`: once per
+    eager call, once per trace under an outer jit.
+    """
+    if csort is None:
+        from repro.kernels.ops import batched_coo_logsumexp
+
+        default_registry.counter("spar_sink.loop_scatter")
+        rows_c, logvals_c = rows, logvals
+        reduce_row = functools.partial(batched_coo_logsumexp, rows, n=n)
+        reduce_col = functools.partial(batched_coo_logsumexp, cols, n=m)
+    else:
+        default_registry.counter("spar_sink.loop_sorted_scan")
+        lay = _sorted_log_layout(rows, cols, logvals, csort, n=n, m=m)
+        rows_c, logvals_c = lay.rows_cs, lay.logvals_cs
+        reduce_row = functools.partial(sparsify.sorted_segment_logsumexp, seg=lay.row_seg)
+        reduce_col = functools.partial(sparsify.sorted_segment_logsumexp, seg=lay.col_seg)
     eps_col = eps[:, None]
 
     def lse_row(g):  # (B, m) -> (B, n)
-        y = g / eps_col
-        z = logvals + jnp.take_along_axis(y, cols, axis=1)
-        return batched_coo_logsumexp(rows, z, n=n, indices_are_sorted=sorted_)
+        return reduce_row(logvals + jnp.take_along_axis(g / eps_col, cols, axis=1))
 
-    def lse_col(f):  # (B, n) -> (B, m)
-        y = f / eps_col
-        z = logvals + jnp.take_along_axis(y, rows, axis=1)
-        if not sorted_:
-            return batched_coo_logsumexp(cols, z, n=m)
-        return batched_coo_logsumexp(
-            cols_sorted,
-            jnp.take_along_axis(z, csort, axis=1),
-            n=m,
-            indices_are_sorted=True,
-        )
+    def lse_col(f):  # (B, n) -> (B, m), summands in column order
+        return reduce_col(logvals_c + jnp.take_along_axis(f / eps_col, rows_c, axis=1))
 
     return batched_sparse_log_loop(
         lse_row, lse_col, loga, logb, eps, fe, tol=tol, max_iter=max_iter,

@@ -47,6 +47,7 @@ __all__ = [
     "BlockEllKernel",
     "FactorDraw",
     "LogSparseKernelCOO",
+    "SortedSegments",
     "SparseKernelCOO",
     "block_ell_matvec",
     "block_ell_rmatvec",
@@ -61,6 +62,8 @@ __all__ = [
     "ot_tile_probs",
     "poisson_keep_probs",
     "segment_logsumexp",
+    "sorted_segment_logsumexp",
+    "sorted_segments",
     "sparsify_block_ell",
     "sparsify_coo",
     "sparsify_coo_log",
@@ -551,10 +554,11 @@ def segment_logsumexp(
     ``-inf`` entries are inert (their ``exp`` shift is masked to 0, so no
     ``-inf - -inf = nan``), and empty / all-dead segments come out exactly
     ``-inf`` — the log-domain mirror of `coo_matvec`'s zero rows. This is
-    the one implementation behind both the per-problem `coo_lse_row` /
-    `coo_lse_col` and the batched flat reduction in ``repro.kernels.ops``
-    (disjoint per-element segments), keeping batched results bitwise equal
-    to per-problem ones.
+    the one implementation behind the sketch's duplicate merge, the
+    unsorted per-problem `coo_lse_row` / `coo_lse_col` and the batched flat
+    reduction in ``repro.kernels.ops`` (disjoint per-element segments),
+    keeping batched results bitwise equal to per-problem ones. Sorted
+    entries take `sorted_segment_logsumexp`, which needs no scatter.
     """
     mx = jax.ops.segment_max(
         z, seg, num_segments=num_segments, indices_are_sorted=indices_are_sorted
@@ -566,29 +570,96 @@ def segment_logsumexp(
     return jnp.where(jnp.isneginf(mx), -jnp.inf, mx + jnp.log(tot))
 
 
+class SortedSegments(NamedTuple):
+    """Where the segments of a sorted segment-id array lie along its last
+    axis, for `sorted_segment_logsumexp`. Leading axes are batch axes."""
+
+    offset: jax.Array  # (..., cap) int32: entry's distance from its segment's first
+    last: jax.Array  # (..., num_segments) int32: the segment's last entry (0 if empty)
+    empty: jax.Array  # (..., num_segments) bool
+    steps: jax.Array  # () int32: doublings that span the longest segment
+
+
+def sorted_segments(idx: jax.Array, num_segments: int) -> SortedSegments:
+    """`SortedSegments` of ``idx``, sorted ascending along its last axis:
+    O(num_segments log cap) binary searches and one O(cap) gather, done
+    once for any number of `sorted_segment_logsumexp` calls on the layout."""
+    ids = jnp.arange(num_segments, dtype=idx.dtype)
+
+    def search(side):
+        fn = partial(jnp.searchsorted, v=ids, side=side)
+        for _ in range(idx.ndim - 1):
+            fn = jax.vmap(fn)
+        return fn(idx).astype(jnp.int32)
+
+    hi, lo = search("right"), search("left")
+    offset = jnp.arange(idx.shape[-1], dtype=jnp.int32) - jnp.take_along_axis(
+        lo, idx, axis=-1
+    )
+    steps = 32 - jax.lax.clz(jnp.max(offset))  # bit length: 2**steps > offset
+    return SortedSegments(offset, jnp.maximum(hi - 1, 0), hi == lo, steps)
+
+
+def _online_lse_combine(ma, sa, mb, sb):
+    """Online-logsumexp combine of ``(max, sum)`` pairs: both sums brought
+    to the larger max. A ``(-inf, 0)`` pair is the identity, exactly, and
+    no ``-inf - -inf`` is formed."""
+    mx = jnp.maximum(ma, mb)
+    e = jnp.exp(jnp.minimum(ma, mb) - jnp.where(jnp.isneginf(mx), 0.0, mx))
+    return mx, jnp.where(ma >= mb, sa + sb * e, sa * e + sb)
+
+
+@jax.jit
+def sorted_segment_logsumexp(z: jax.Array, seg: SortedSegments) -> jax.Array:
+    """Per-segment ``logsumexp`` of entries already grouped by segment along
+    the last axis, with no scatter: a segmented scan of online ``(max,
+    sum)`` pairs, read at each segment's last entry.
+
+    The scan doubles its reach ``seg.steps`` times: at reach ``d`` an entry
+    at least ``d`` past its segment's first takes in the pair ``d`` before
+    it. Each step is one elementwise pass over a rotated copy, and what an
+    entry takes in depends only on its offset in its segment, so a row of a
+    batch, or a longer padded row, gives the same bits.
+
+    Matches `segment_logsumexp` to about a ulp (only the rounding order
+    inside a segment differs); ``-inf`` entries are inert and empty or
+    all-``-inf`` segments come out exactly ``-inf``. Jitted, so a loop
+    body traced anew on every eager solve reuses the traced scan.
+    """
+    off = seg.offset.reshape(-1)
+    mx = z.reshape(-1)
+    tot = jnp.where(jnp.isneginf(mx), 0.0, 1.0).astype(z.dtype)
+
+    def step(k, carry):
+        mx, tot = carry
+        d = jnp.left_shift(jnp.int32(1), k)
+        m2, s2 = _online_lse_combine(jnp.roll(mx, d), jnp.roll(tot, d), mx, tot)
+        take = off >= d  # never true within d of a row's start: no wrap is read
+        return jnp.where(take, m2, mx), jnp.where(take, s2, tot)
+
+    mx, tot = jax.lax.fori_loop(0, seg.steps, step, (mx, tot))
+    mx = jnp.take_along_axis(mx.reshape(z.shape), seg.last, axis=-1)
+    tot = jnp.take_along_axis(tot.reshape(z.shape), seg.last, axis=-1)
+    return jnp.where(seg.empty | jnp.isneginf(mx), -jnp.inf, mx + jnp.log(tot))
+
+
 def coo_lse_row(sk: LogSparseKernelCOO, y: jax.Array) -> jax.Array:
     """``logsumexp_j(logvals_e + y[cols_e])`` per row in O(cap) — the
-    log-domain `coo_matvec` (callers pass ``y = g/eps``)."""
-    return segment_logsumexp(
-        sk.logvals + y[sk.cols],
-        sk.rows,
-        num_segments=sk.n,
-        indices_are_sorted=sk.csort is not None,
-    )
+    log-domain `coo_matvec` (callers pass ``y = g/eps``). Construction-
+    sorted sketches take `sorted_segment_logsumexp`, as the solvers do."""
+    z = sk.logvals + y[sk.cols]
+    if sk.csort is None:
+        return segment_logsumexp(z, sk.rows, num_segments=sk.n)
+    return sorted_segment_logsumexp(z, sorted_segments(sk.rows, sk.n))
 
 
 def coo_lse_col(sk: LogSparseKernelCOO, y: jax.Array) -> jax.Array:
     """``logsumexp_i(logvals_e + y[rows_e])`` per column in O(cap) — the
     log-domain `coo_rmatvec`; runs the col-sorted permutation when available."""
-    z = sk.logvals + y[sk.rows]
     if sk.csort is None:
-        return segment_logsumexp(z, sk.cols, num_segments=sk.m)
-    return segment_logsumexp(
-        z[sk.csort],
-        sk.cols[sk.csort],
-        num_segments=sk.m,
-        indices_are_sorted=True,
-    )
+        return segment_logsumexp(sk.logvals + y[sk.rows], sk.cols, num_segments=sk.m)
+    z = sk.logvals[sk.csort] + y[sk.rows[sk.csort]]
+    return sorted_segment_logsumexp(z, sorted_segments(sk.cols[sk.csort], sk.m))
 
 
 # --------------------------------------------------------------------------

@@ -250,13 +250,12 @@ def batched_coo_rmatvec(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("n", "indices_are_sorted"))
+@functools.partial(jax.jit, static_argnames=("n",))
 def batched_coo_logsumexp(
     idx: jax.Array,
     z: jax.Array,
     *,
     n: int | None = None,
-    indices_are_sorted: bool = False,
 ) -> jax.Array:
     """B independent padded-COO segment-logsumexps as one flat reduction.
 
@@ -268,7 +267,8 @@ def batched_coo_logsumexp(
     single `repro.core.sparsify.segment_logsumexp` implementation, so
     results are bitwise those of B separate per-problem calls; ``-inf``
     entries (padding / dead sketch slots) are inert and empty segments come
-    out exactly ``-inf``. Returns (B, n).
+    out exactly ``-inf``. Returns (B, n). Sorted segments take the scan
+    `repro.core.sparsify.sorted_segment_logsumexp` instead, with no scatter.
     """
     from repro.core.sparsify import segment_logsumexp
 
@@ -276,12 +276,7 @@ def batched_coo_logsumexp(
     if n is None:
         raise TypeError("batched_coo_logsumexp requires n (static output width)")
     seg = (idx + (jnp.arange(bsz, dtype=jnp.int32) * n)[:, None]).ravel()
-    out = segment_logsumexp(
-        z.ravel(),
-        seg,
-        num_segments=bsz * n,
-        indices_are_sorted=indices_are_sorted,
-    )
+    out = segment_logsumexp(z.ravel(), seg, num_segments=bsz * n)
     return out.reshape(bsz, n)
 
 

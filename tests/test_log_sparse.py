@@ -14,12 +14,15 @@ Covers the tentpole and its acceptance criteria:
   and the unified ``tol`` default across registered methods.
 """
 import inspect
+import zlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _hlo import has_scatter, while_body_text
+from repro.batch import solvers as batch_solvers
 from repro.core import (
     Geometry,
     OTProblem,
@@ -455,3 +458,80 @@ def test_generic_sparse_log_loop_matches_solver_trajectory():
     f_ref, f_sol = np.asarray(res.u), np.asarray(sol.result.u)
     alive = ~np.isneginf(f_ref)
     np.testing.assert_allclose(f_sol[alive], f_ref[alive], rtol=1e-12, atol=1e-12)
+
+
+# --------------------------------------------------------------------------
+# The sorted segmented logsumexp of the iteration loop
+# --------------------------------------------------------------------------
+
+
+def _sorted_case(name):
+    """``(idx, z, n)``: (B, cap) sorted segment ids and float32 summands."""
+    rng = np.random.default_rng(zlib.crc32(name.encode()))
+    n, cap = 40, 400
+
+    def draw(k):
+        return np.sort(rng.integers(0, n, size=k)), rng.normal(size=k) * 30 - 300
+
+    if name in ("random_b1", "random_b3"):
+        rows = [draw(cap) for _ in range(1 if name == "random_b1" else 3)]
+    elif name == "empty_segments":  # ids from a few segments only
+        idx = np.sort(rng.choice([2, 3, 9, 30], size=cap))
+        rows = [(idx, rng.normal(size=cap) * 30 - 300)]
+    elif name == "all_neginf_segments":
+        idx, z = draw(cap)
+        z[np.isin(idx, [0, 5, 6, n - 1])] = -np.inf
+        rows = [(idx, z)]
+    elif name == "single_entry_segments":
+        idx = np.arange(n)[rng.random(n) < 0.7]
+        rows = [(idx, rng.normal(size=idx.size) * 30 - 300)]
+    elif name == "dead_tail":  # the mf sketch parks dead slots at row n - 1
+        idx, z = draw(cap // 4)
+        rows = [(np.concatenate([idx, np.full(cap - cap // 4, n - 1)]),
+                 np.concatenate([z, np.full(cap - cap // 4, -np.inf)]))]
+    else:  # "starts_at_entry_0": one long first segment, then short ones
+        idx = np.concatenate([np.zeros(300, int), np.sort(rng.integers(1, n, 100))])
+        rows = [(idx, rng.normal(size=400) * 30 - 300)]
+    for _, z in rows:
+        z[rng.random(z.size) < 0.1] = -np.inf
+    idx = jnp.asarray(np.stack([r[0] for r in rows]), jnp.int32)
+    z = jnp.asarray(np.stack([r[1] for r in rows]), jnp.float32)
+    return idx, z, n
+
+
+@pytest.mark.parametrize("name", [
+    "random_b1", "random_b3", "empty_segments", "all_neginf_segments",
+    "single_entry_segments", "dead_tail", "starts_at_entry_0",
+])
+def test_sorted_segment_logsumexp_matches_scatter(name):
+    """The loop's scan against the scatter `segment_logsumexp`, per row of
+    the batch: a few float32 ulps, and ``-inf`` exactly where it gives it."""
+    idx, z, n = _sorted_case(name)
+    got = np.asarray(sparsify.sorted_segment_logsumexp(z, sparsify.sorted_segments(idx, n)))
+    for b in range(idx.shape[0]):
+        ref = np.asarray(sparsify.segment_logsumexp(z[b], idx[b], n))
+        assert got[b].dtype == np.float32
+        np.testing.assert_array_equal(np.isneginf(got[b]), np.isneginf(ref))
+        fin = np.isfinite(ref)
+        assert fin.any()
+        ulps = np.abs(got[b][fin] - ref[fin]) / np.spacing(np.abs(ref[fin]))
+        assert ulps.max() <= 4, ulps.max()
+
+
+def _loop_hlo(sorted_: bool) -> str:
+    n, cap = 32, 256
+    ids = jax.ShapeDtypeStruct((1, cap), jnp.int32)
+    vec = jax.ShapeDtypeStruct((1, n), jnp.float32)
+    one = jax.ShapeDtypeStruct((1,), jnp.float32)
+    loop = jax.jit(batch_solvers.sparse_log_potentials,
+                   static_argnames=("n", "m", "tol", "max_iter"))
+    return loop.lower(
+        ids, ids, jax.ShapeDtypeStruct((1, cap), jnp.float32), ids if sorted_ else None,
+        vec, vec, one, one, n=n, m=n, tol=1e-6, max_iter=10,
+    ).compile().as_text()
+
+
+def test_sorted_loop_body_holds_no_scatter():
+    """With ``csort`` the loop reduces by the scan; without it, by scatters."""
+    assert not has_scatter(while_body_text(_loop_hlo(True)))
+    assert has_scatter(while_body_text(_loop_hlo(False)))

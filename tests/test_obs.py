@@ -556,6 +556,18 @@ def test_spar_sink_phases_record_once_into_injected_registry(method, opts, certi
     assert counts == {f"{p}_seconds": 1 for p in want}
 
 
+def test_stabilized_solve_counts_the_sorted_scan_loop(monkeypatch):
+    """A sorted sketch's loop takes the scan, and says so once a call."""
+    import repro.batch.solvers
+
+    reg = MetricsRegistry()
+    monkeypatch.setattr(repro.batch.solvers, "default_registry", reg)
+    solve(_cloud(), method="spar_sink_mf", stabilize=True, key=jax.random.PRNGKey(0),
+          s=2000.0)
+    assert reg.get_counter("spar_sink.loop_sorted_scan") == 1
+    assert reg.get_counter("spar_sink.loop_scatter") == 0
+
+
 def test_spar_sink_spans_change_no_result_and_nest_in_the_trace(tmp_path):
     from jax.profiler import ProfileData
 

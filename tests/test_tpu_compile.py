@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from _hlo import has_scatter, while_body_text
 from repro.batch.problems import BatchedProblem
 from repro.batch.solvers import (
     build_batched_log_sketch,
@@ -84,7 +85,8 @@ def test_gathered_kernel_fits_v5e(one_chip, cost):
 
 def test_sparse_log_loop_compiles_v5e(one_chip):
     """The log-domain iteration behind ``spar_sink_mf(stabilize=True)`` at
-    n = 2^17 with the advertised sketch capacity (B = 1)."""
+    n = 2^17 with the advertised sketch capacity (B = 1): the sorted
+    sketch's loop reduces by the segmented scan, with no scatter left."""
     loop = jax.jit(sparse_log_potentials,
                    static_argnames=("n", "m", "tol", "max_iter"))
     ids = _spec((1, CAP_LARGE), jnp.int32, one_chip)
@@ -95,6 +97,7 @@ def test_sparse_log_loop_compiles_v5e(one_chip):
         one, one, n=N_LARGE, m=N_LARGE, tol=1e-6, max_iter=1000,
     ).compile()
     assert _temp_bytes(compiled) < HBM_BYTES
+    assert not has_scatter(while_body_text(compiled.as_text()))
 
 
 def _batched_spar_sink_log_args(batch: int, n: int):
