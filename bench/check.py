@@ -12,8 +12,11 @@ entries there, its potentials and its value. Per answer the reference
    costs and the sampling rates;
 2. iterates its own Sinkhorn on that support with its own log-values (and
    the ``k`` read in 1), by the configuration's stopping rule, evaluates
-   eq. 6 / eq. 10, and compares the value: ``value_rel_err``. It covers the
-   iteration loop, its iteration count and the objective;
+   eq. 6 / eq. 10, and compares the value: ``value_rel_err``, the gap over
+   the reference's value or, where that is smaller, over ``eps`` times the
+   lesser marginal mass (the scale of the entropy term), so that a value
+   near 0 still has a scale. It covers the iteration loop, its iteration
+   count and the objective;
 3. where the cell asks for it, tests the draw itself, pooled over the
    answers checked: ``draw_z``, the larger of two standardized statistics.
    One is Pearson's, of the support against the reference's probability
@@ -107,9 +110,10 @@ def check_answer(m, ans: Answer, s: float, *, tol: float, max_iter: int) -> dict
                        np.full(pad, -np.inf))
     rows[:ans.nnz], cols[:ans.nnz], lvp[:ans.nnz] = ans.rows, ans.cols, lv
     f, g, _ = reference.solve_support(m, rows, cols, lvp, tol=tol, max_iter=max_iter)
-    c_e = reference.entry_cost(m.x, ans.rows, ans.cols)
+    c_e = reference.entry_cost(m, ans.rows, ans.cols)
     ref_value = reference.objective(m, ans.rows, ans.cols, lv, f, g, c_e)
-    rel = abs(ans.value - ref_value) / max(abs(ref_value), 1e-30)
+    floor = m.eps * float(min(m.a.sum(), m.b.sum()))
+    rel = abs(ans.value - ref_value) / max(abs(ref_value), floor)
     return {"entry_log_err": err, "value_rel_err": rel if math.isfinite(rel) else math.inf,
             "ref_value": ref_value, "extra_draws": extra_draws(m, ans, ref_lv, k)}
 

@@ -38,7 +38,7 @@ def answer(m, key, s: float, dtype, tol: float, max_iter: int) -> check.Answer:
     rnd = lambda v: np.asarray(jnp.asarray(v, dtype), np.float64)  # noqa: E731
     with np.errstate(invalid="ignore", over="ignore"):
         t = rnd(np.exp(rnd(lv + (f[rows] + g[cols]) / m.eps)))
-    c_e = rnd(reference.entry_cost(m.x, rows, cols))
+    c_e = rnd(reference.entry_cost(m, rows, cols))
     value = reference.objective(m, rows, cols, rnd(lv), rnd(f), rnd(g), c_e)
     return check.Answer(rows, cols, np.nan_to_num(t, nan=0.0), f, g, float(rnd(value)), nnz)
 
@@ -49,8 +49,9 @@ def readings(name: str, seed: int, *, dtype=jnp.bfloat16, root=harness.REPO,
     many problems of the seed's pool as a run checks."""
     cell, params = harness.load_cell(name, root)
     params.update(overrides or {})
-    ms = generator.solve_pool(params, seed)[: cell["check"]["solves"]]
-    s = params["s_mult"] * generator.s0(params["n"])
+    traffic = harness.traffic(cell["traffic"], root)
+    ms = traffic.pool(params, seed, root)[: cell["check"]["solves"]]
+    s = traffic.budget(params, ms)
     base = jax.random.PRNGKey(generator.key_seed(seed, 9))
     samples = [(m, answer(m, jax.random.fold_in(base, i), s, dtype, params["tol"],
                           params["max_iter"]))

@@ -6,9 +6,15 @@ Everything is found by name, with no registry to edit:
   reports;
 * ``bench/workloads/<cell>.json`` names the cell's configuration, its
   traffic kind, the mix's parameters, what to check and the limits;
-* ``bench/configs/<config>.json`` holds the deployment's sizes;
+* ``bench/configs/<config>.json`` holds the deployment's sizes, and under
+  ``params["family"]`` the problem family it draws (none:
+  ``pointcloud_c1``);
+* ``bench/families/<family>.py`` draws the problems and gives their ground
+  cost, to the traffic, the reference and the control (``pool``,
+  ``cost``, ``geometry``, ``TINY``, ``TINY_MERGE``);
 * ``bench/traffic/<kind>.py`` drives the system under test with the mix
-  (``setup``, ``run``, ``describe``, ``failed``, ``collect``, ``verify``);
+  (``pool``, ``setup``, ``run``, ``describe``, ``failed``, ``collect``,
+  ``verify``, ``TINY``);
 * ``bench/metrics/<metric>.py`` reads one metric from the run (``read``),
   and returns None where it finds nothing to read.
 """
@@ -69,6 +75,11 @@ def _module(path: Path):
 
 def traffic(kind: str, root: Path = REPO):
     return _module(root / "bench" / "traffic" / f"{kind}.py")
+
+
+def family(params: dict, root: Path = REPO):
+    """The problem family that a cell's parameters name."""
+    return _module(root / "bench" / "families" / f"{params.get('family', 'pointcloud_c1')}.py")
 
 
 def reader(metric: str, root: Path = REPO):
@@ -159,7 +170,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
     if device is None:
         device = check_device(chips_of(benchmark, name))
     mod = traffic(cell["traffic"], root)
-    state = mod.setup(params, seed, seconds, log)
+    state = mod.setup(params, seed, seconds, log, root)
     setup_s = time.perf_counter() - t_start
 
     trace_dir = root / "bench" / "_out" / "trace"

@@ -1,13 +1,16 @@
-"""Plain reference of the point-cloud configuration: entropic OT and UOT on
-an importance sketch, written from the paper (arXiv:2306.06581, eqs. 6, 7,
-9, 10, 11) in straightforward ``jax.numpy``. It imports nothing of the
-program.
+"""Plain reference of the configurations: entropic OT and UOT on an
+importance sketch, written from the paper (arXiv:2306.06581, eqs. 6, 7, 9,
+10, 11) in straightforward ``jax.numpy``. It imports nothing of the
+program. The ground cost is the problem's family's
+(``bench/families/<family>.py``, `bench.generator.Measures.cost`); a cost
+of ``+inf`` blocks a pair: its rate is 0 and it is never kept.
 
 * `row_factors`, `single_logvals`, `draw_chi2`, `dup_moments`: what the
   matrix-free Poissonized sketch of ``spar_sink_mf`` should be. Pair ij is
   drawn ``k_ij ~ Poisson(r_ij)`` times, ``r_ij = s p_ij`` (OT, eq. 9) or the
   UOT proposal's rate thinned by ``exp(-C_ij/(2 lam + eps))`` (eq. 11), and
-  a pair drawn ``k`` times weighs ``k`` single draws.
+  a pair drawn ``k`` times weighs ``k`` single draws. Support points of
+  zero mass have rate 0 in their row or column.
 * `solve_support`: log-domain Sinkhorn (OT) or its UOT form on a given
   support, dense and masked, so it runs fast on a TPU; `objective`: eq. 6 /
   eq. 10 on that support.
@@ -34,17 +37,23 @@ NEG_INF = -np.inf
 # --------------------------------------------------------------------------
 
 
-def pair_cost(xr: jax.Array, xc: jax.Array) -> jax.Array:
-    """Squared Euclidean cost of row points ``xr`` (k, d) against column
-    points ``xc`` (l, d) as a (k, l) block, from coordinate differences."""
-    diff = xr[:, None, :] - xc[None, :, :]
-    return jnp.sum(diff * diff, axis=-1)
+def block_cost(family, cost_kw: tuple, xr: jax.Array, xc: jax.Array) -> jax.Array:
+    """The family's cost of row points ``xr`` (k, d) against column points
+    ``xc`` (l, d) as a (k, l) block, in their dtype."""
+    return family.cost(jnp, xr[:, None, :], xc[None, :, :], **dict(cost_kw))
 
 
-def entry_cost(x: np.ndarray, rows: np.ndarray, cols: np.ndarray) -> np.ndarray:
-    """Squared Euclidean cost of the pairs (rows, cols), float64, host."""
-    d = x[rows] - x[cols]
-    return np.sum(d * d, axis=-1)
+def entry_cost(m, rows: np.ndarray, cols: np.ndarray) -> np.ndarray:
+    """The cost of the pairs (rows, cols) of problem ``m``, float64, host."""
+    return m.cost(np, m.x[rows], m.x[cols])
+
+
+def _lograte(lrb, lc, thin, c):
+    """``log r`` of a block with cost ``c``: ``-inf`` where the pair is
+    blocked (``c = +inf``) or a marginal is zero, with no ``inf - inf``."""
+    blocked = jnp.isinf(c)
+    return jnp.where(blocked, -jnp.inf,
+                     lrb[:, None] + lc[None, :] - thin * jnp.where(blocked, 0.0, c))
 
 
 def _log(v: np.ndarray) -> np.ndarray:
@@ -79,8 +88,8 @@ def _f32(v):
     return jnp.asarray(v, jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "groups"))
-def _blocked_sums(x, lr, lc, thin, rg, cg, *, block, groups):
+@functools.partial(jax.jit, static_argnames=("block", "groups", "family", "cost_kw"))
+def _blocked_sums(x, lr, lc, thin, rg, cg, *, block, groups, family, cost_kw):
     """Per-(row group, column group) sums of the probability ``P = 1 -
     exp(-r)`` that a pair is drawn at least once and of ``P (1 - P)``, plus
     the row and column sums of P, over the dense (n, n) pair grid in row
@@ -93,7 +102,7 @@ def _blocked_sums(x, lr, lc, thin, rg, cg, *, block, groups):
         r0 = i * block
         xr = jax.lax.dynamic_slice_in_dim(x, r0, block)
         lrb = jax.lax.dynamic_slice_in_dim(lr, r0, block)
-        p = -jnp.expm1(-jnp.exp(lrb[:, None] + lc[None, :] - thin * pair_cost(xr, x)))
+        p = -jnp.expm1(-jnp.exp(_lograte(lrb, lc, thin, block_cost(family, cost_kw, xr, x))))
         rgb = jax.lax.dynamic_slice_in_dim(rg, r0, block)
         ohr = jax.nn.one_hot(rgb, groups, dtype=p.dtype)
         ohc = jax.nn.one_hot(cg, groups, dtype=p.dtype)
@@ -120,14 +129,16 @@ def draw_chi2(m, s: float, rows, cols, max_groups=16):
     n = m.n
     lr, lc, thin = row_factors(m, s)
     args = (_f32(m.x), _f32(lr), _f32(lc), _f32(thin))
+    static = dict(block=_block(n), family=m.family, cost_kw=m.cost_kw)
     zeros = jnp.zeros((n,), jnp.int32)
-    _, _, rsum, csum = _blocked_sums(*args, zeros, zeros, block=_block(n), groups=1)
+    _, _, rsum, csum = _blocked_sums(*args, zeros, zeros, groups=1, **static)
     total = float(jnp.sum(rsum))
     groups = int(max(1, min(max_groups, math.isqrt(int(total / 50.0)))))
     rg = _groups(np.asarray(rsum, np.float64), groups)
     cg = _groups(np.asarray(csum, np.float64), groups)
-    cell, var, _, _ = _blocked_sums(*args, jnp.asarray(rg), jnp.asarray(cg),
-                                    block=_block(n), groups=groups)
+    groups = int(max(rg.max(), cg.max())) + 1
+    cell, var, _, _ = _blocked_sums(*args, jnp.asarray(rg), jnp.asarray(cg), groups=groups,
+                                    **static)
     obs = np.zeros((groups, groups))
     np.add.at(obs, (rg[rows], cg[cols]), 1.0)
     e, v = np.asarray(cell, np.float64), np.asarray(var, np.float64)
@@ -138,9 +149,17 @@ def draw_chi2(m, s: float, rows, cols, max_groups=16):
 
 
 def _groups(expect: np.ndarray, groups: int) -> np.ndarray:
-    """Contiguous index groups of about equal total ``expect``."""
+    """Contiguous index groups of about equal total ``expect``, at most
+    ``groups``, numbered from 0 with none empty. An index of zero
+    ``expect`` joins the group of the nearest index before it with a
+    positive one (after it, at the start), so no group's total is zero."""
     c = np.cumsum(expect)
-    return np.minimum((c - 0.5 * expect) * groups // max(c[-1], 1e-300), groups - 1).astype(np.int32)
+    g = np.minimum((c - 0.5 * expect) * groups // max(c[-1], 1e-300), groups - 1)
+    live = expect > 0
+    if live.any():
+        prev = np.maximum.accumulate(np.where(live, np.arange(expect.size), -1))
+        g = g[np.where(prev >= 0, prev, np.argmax(live))]
+    return np.unique(g, return_inverse=True)[1].astype(np.int32)
 
 
 def dup_pair_moments(r):
@@ -157,18 +176,19 @@ def dup_pair_moments(r):
     return mean, var
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def _dup_sums(x, lr, lc, thin, inv_eps, fe, ge, logfloor, *, block):
+@functools.partial(jax.jit, static_argnames=("block", "family", "cost_kw"))
+def _dup_sums(x, lr, lc, thin, inv_eps, fe, ge, logfloor, *, block, family, cost_kw):
     n = x.shape[0]
 
     def one(i, acc):
         r0 = i * block
         xr = jax.lax.dynamic_slice_in_dim(x, r0, block)
-        c = pair_cost(xr, x)
-        logr = jax.lax.dynamic_slice_in_dim(lr, r0, block)[:, None] + lc[None, :] - thin * c
-        single = -c * inv_eps - logr
+        c = block_cost(family, cost_kw, xr, x)
+        logr = _lograte(jax.lax.dynamic_slice_in_dim(lr, r0, block), lc, thin, c)
+        live = jnp.isfinite(logr)  # a pair of rate 0 is never drawn
+        single = -jnp.where(live, c, 0.0) * inv_eps - jnp.where(live, logr, 0.0)
         feb = jax.lax.dynamic_slice_in_dim(fe, r0, block)
-        readable = single + feb[:, None] + ge[None, :] >= logfloor
+        readable = live & (single + feb[:, None] + ge[None, :] >= logfloor)
         mean, var = dup_pair_moments(jnp.exp(logr))
         return (acc[0] + jnp.sum(jnp.where(readable, mean, 0.0)),
                 acc[1] + jnp.sum(jnp.where(readable, var, 0.0)))
@@ -185,16 +205,22 @@ def dup_moments(m, s: float, f: np.ndarray, g: np.ndarray, logfloor: float):
     with np.errstate(invalid="ignore"):
         fe, ge = f / m.eps, g / m.eps
     mean, var = _dup_sums(_f32(m.x), _f32(lr), _f32(lc), _f32(thin), _f32(1.0 / m.eps),
-                          _f32(fe), _f32(ge), _f32(logfloor), block=_block(m.n))
+                          _f32(fe), _f32(ge), _f32(logfloor), block=_block(m.n),
+                          family=m.family, cost_kw=m.cost_kw)
     return float(mean), float(var)
 
 
 def single_logvals(m, s: float, rows, cols):
     """Reference log-value of one draw of each pair, ``-C/eps - log r``,
-    float64, host."""
+    float64, host; ``-inf`` (no weight) for a pair of rate 0, which no sound
+    draw keeps."""
     lr, lc, thin = row_factors(m, s)
-    c = entry_cost(m.x, rows, cols)
-    return -c / m.eps - (lr[rows] + lc[cols] - thin * c)
+    c = entry_cost(m, rows, cols)
+    out = np.full(c.shape, NEG_INF)
+    live = np.isfinite(c) & np.isfinite(lr[rows]) & np.isfinite(lc[cols])
+    c, rows, cols = c[live], rows[live], cols[live]
+    out[live] = -c / m.eps - (lr[rows] + lc[cols] - thin * c)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -254,6 +280,9 @@ def objective(m, rows, cols, logvals, f, g, c_e) -> float:
 
 
 def _kl(x: np.ndarray, y: np.ndarray) -> float:
+    """``KL(x | y) = sum x log(x / y) - x + y``; ``+inf`` where ``x > 0 = y``."""
+    if np.any((x > 0) & (y <= 0)):
+        return math.inf
     with np.errstate(divide="ignore", invalid="ignore"):
         xl = np.where(x > 0, x * (_log(np.where(x > 0, x, 1.0)) - _log(np.where(y > 0, y, 1.0))), 0.0)
     return float(np.sum(xl - x + y))
@@ -295,13 +324,15 @@ def draw(key, m, s: float, dtype):
     u = jax.random.uniform(k2, (rows.size,), jnp.float32).astype(dtype)
     cdf = jnp.cumsum(rb.astype(dtype))
     cols = np.minimum(np.asarray(jnp.searchsorted(cdf, u, side="right")), m.n - 1)
-    c = jnp.sum((x[rows] - x[cols]) ** 2, axis=-1)
+    c = m.cost(jnp, x[rows], x[cols])
+    blocked = np.asarray(jnp.isinf(c))
+    c = jnp.where(blocked, 0.0, c)  # a blocked draw is never kept
     lograte = (jnp.log(jnp.asarray(s, dtype)) + jnp.log(ra)[rows] + jnp.log(rb)[cols]
                - jnp.asarray(thin, dtype) * c)
-    keep = np.ones(rows.size, bool)
+    keep = ~blocked & np.asarray(jnp.isfinite(lograte))
     if thin:
-        keep = np.asarray(jnp.log(jax.random.uniform(k3, (rows.size,), jnp.float32)).astype(dtype)
-                          < -jnp.asarray(thin, dtype) * c)
+        keep &= np.asarray(jnp.log(jax.random.uniform(k3, (rows.size,), jnp.float32)).astype(dtype)
+                           < -jnp.asarray(thin, dtype) * c)
     lv = np.asarray(-c / jnp.asarray(m.eps, dtype) - lograte, np.float64)[keep]
     rows, cols = rows[keep], cols[keep]
     pair = rows.astype(np.int64) * m.n + cols

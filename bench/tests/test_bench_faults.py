@@ -13,12 +13,9 @@ import repro.core.sparsify as sparsify
 from bench.tests import tiny
 
 CELLS = tiny.cells()
-#: a size with enough duplicate draws for a lost merge to show: their
-#: count grows about as log^8 n, so at the tiny size it reads near noise
-MERGE_N = 2048
 
 
-def _unchanged_state(rows, cols, logvals, csort, loga, logb, eps, fe, **kw):
+def unchanged_state(rows, cols, logvals, csort, loga, logb, eps, fe, **kw):
     """The iteration returns its initial potentials, as if no step ran."""
     f = jnp.where(jnp.isneginf(loga), -jnp.inf, 0.0).astype(loga.dtype)
     g = jnp.where(jnp.isneginf(logb), -jnp.inf, 0.0).astype(logb.dtype)
@@ -33,7 +30,7 @@ def _assert_caught(res):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_state_left_unchanged(cell, monkeypatch):
-    monkeypatch.setattr(batch_solvers, "sparse_log_potentials", _unchanged_state)
+    monkeypatch.setattr(batch_solvers, "sparse_log_potentials", unchanged_state)
     _assert_caught(tiny.run(cell))
 
 
@@ -66,7 +63,7 @@ def test_duplicate_draws_lose_their_multiplicity(cell, monkeypatch):
     _merge_fault(monkeypatch, lambda z, seg, num_segments, indices_are_sorted=False:
                  jax.ops.segment_max(z, seg, num_segments=num_segments,
                                      indices_are_sorted=indices_are_sorted))
-    res = tiny.run(cell, n=MERGE_N)
+    res = tiny.run(cell, merge=True)
     _assert_caught(res)
     assert res["checks"]["entry_log_err"]["value"] <= res["checks"]["entry_log_err"]["limit"]
 
@@ -76,4 +73,4 @@ def test_every_multiplicity_doubled(cell, monkeypatch):
     """A pair drawn k times weighs 2k draws."""
     real = sparsify.segment_logsumexp
     _merge_fault(monkeypatch, lambda *a, **k: real(*a, **k) + math.log(2.0))
-    _assert_caught(tiny.run(cell, n=MERGE_N))
+    _assert_caught(tiny.run(cell, merge=True))

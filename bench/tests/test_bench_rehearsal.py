@@ -25,7 +25,7 @@ def test_cell_runs_and_is_correct(cell):
     json.dumps(res, allow_nan=False)
 
 
-@pytest.mark.parametrize("kind", ["solve_stream"])
+@pytest.mark.parametrize("kind", sorted({harness.load_cell(c)[0]["traffic"] for c in tiny.cells()}))
 def test_traced_run_adds_breakdown_and_reads_per_layer_metrics(kind):
     bench = harness.read_json(harness.REPO / "BENCHMARK.json")
     for cell in [c for c in tiny.cells() if harness.load_cell(c)[0]["traffic"] == kind]:

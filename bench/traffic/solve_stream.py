@@ -1,23 +1,30 @@
 """Solves back to back: one caller, one large problem at a time.
 
-The cell's pool of problems (`bench.generator.solve_pool`), drawn from the
-seed, is built in set-up and held on the device. Solve ``i`` takes pool
-problem ``i mod pool`` with a key of its own, ``fold_in(key, i)``, so every
-solve draws a fresh sketch. Every solve runs the cell's ``max_iter``
-iterations, so every seed does the same work. Each solve is timed from the
-call to its value on the host; no solve starts after the window's last
-second.
+The cell's pool of problems (`pool`: ``pool`` problems that the
+configuration's family draws from the seed) is built in set-up and held on
+the device. Solve ``i`` takes pool problem ``i mod pool`` with a key of its
+own, ``fold_in(key, i)``, so every solve draws a fresh sketch. Every solve
+runs the cell's ``max_iter`` iterations, so every seed does the same work.
+Each solve is timed from the call to its value on the host; no solve starts
+after the window's last second. After the window, `describe` gives each
+phase's host seconds over the window's solves (the ``spar_sink.`` spans'
+histograms, reset after the warm-up), so a far-off solve shows its phase.
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 
-from bench import generator
+from bench import generator, harness
 from bench.check import Answer, check_all
+
+#: the pool a CPU test holds
+TINY = {"pool": 2}
+PHASES = "spar_sink."
 
 
 @dataclass
@@ -30,10 +37,21 @@ class State:
     solutions: list = field(default_factory=list)
 
 
+def pool(params: dict, seed: int, root=harness.REPO) -> list:
+    """The cell's pool of `generator.Measures`, drawn by its family."""
+    fam = harness.family(params, root)
+    return [dataclasses.replace(m, family=fam) for m in fam.pool(params, seed)]
+
+
+def budget(params: dict, pool: list) -> float:
+    """The sketch budget ``s = s_mult s0(n)``."""
+    return params["s_mult"] * generator.s0(pool[0].n)
+
+
 def _program_problem(m):
     from repro.core import OTProblem, PointCloudGeometry, UOTProblem
 
-    geom = PointCloudGeometry(jnp.asarray(m.x, jnp.float32))
+    geom = PointCloudGeometry(jnp.asarray(m.x, jnp.float32), **m.geometry())
     a, b = jnp.asarray(m.a, jnp.float32), jnp.asarray(m.b, jnp.float32)
     if m.lam is None:
         return OTProblem(geom, a, b, m.eps)
@@ -48,18 +66,21 @@ def _solve(state: State, problem, key):
                  tol=p["tol"], max_iter=p["max_iter"])
 
 
-def setup(params: dict, seed: int, seconds: float, log) -> State:
+def setup(params: dict, seed: int, seconds: float, log, root=harness.REPO) -> State:
+    from repro.obs import default_registry
+
     t0 = time.perf_counter()
-    pool = generator.solve_pool(params, seed)
-    problems = [_program_problem(m) for m in pool]
+    ms = pool(params, seed, root)
+    problems = [_program_problem(m) for m in ms]
     jax.block_until_ready([(q.a, q.b, q.geom.x) for q in problems])
     base = jax.random.PRNGKey(generator.key_seed(seed, 1))
-    state = State(params, pool, problems, base, params["s_mult"] * generator.s0(params["n"]))
+    state = State(params, ms, problems, base, budget(params, ms))
     t1 = time.perf_counter()
     # warm-up: the cell's one shape, pool problem 0 with a key of its own
     warm_key = jax.random.fold_in(jax.random.PRNGKey(generator.key_seed(seed, 2)), 0)
     warm = _solve(state, problems[0], warm_key)
     float(warm.value)
+    default_registry.reset(PHASES)
     t2 = time.perf_counter()
     log(f"setup_parts data_s={t1 - t0:.6f} warm_solve_s={t2 - t1:.6f}")
     return state
@@ -90,9 +111,18 @@ def run(state: State, seconds: float, tracer) -> dict:
 
 
 def describe(record: dict) -> str:
+    """Each solve's seconds, iterations, status and nnz; then, one line a
+    phase, the count, mean and p99 of its host seconds over the window."""
+    from repro.obs import default_registry
+
     calls = record["calls"]
-    return "solves " + " ".join(
-        f"[{c['end'] - c['start']:.6f}s it={c['n_iter']} {c['status']} nnz={c['nnz']}]" for c in calls)
+    lines = ["solves " + " ".join(
+        f"[{c['end'] - c['start']:.6f}s it={c['n_iter']} {c['status']} nnz={c['nnz']}]"
+        for c in calls)]
+    for name, h in sorted(default_registry.snapshot()["histograms"].items()):
+        if name.startswith(PHASES):
+            lines.append(f"phase {name} count={h['count']} mean_s={h['mean']!r} p99_s={h['p99']!r}")
+    return "\n".join(lines)
 
 
 def failed(record: dict, state: State) -> int:
